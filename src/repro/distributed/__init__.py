@@ -9,7 +9,6 @@ from .sharding import (
     current_rules,
     logical_sharding,
     logical_spec,
-    shard_map_compat,
     tree_logical_sharding,
     tree_shardings,
 )
@@ -17,6 +16,6 @@ from .sharding import (
 __all__ = [
     "AxisRules", "INFER_RULES", "LONG_DECODE_RULES", "TRAIN_RULES",
     "axis_rules", "constrain", "current_mesh", "current_rules",
-    "logical_sharding", "logical_spec", "shard_map_compat",
-    "tree_logical_sharding", "tree_shardings",
+    "logical_sharding", "logical_spec", "tree_logical_sharding",
+    "tree_shardings",
 ]

@@ -90,19 +90,6 @@ def current_mesh() -> Optional[Mesh]:
     return _CTX.mesh
 
 
-def shard_map_compat(body, *, mesh, in_specs, out_specs,
-                     check_vma: bool = True):
-    """``jax.shard_map`` across jax versions: the top-level API (with its
-    ``check_vma`` flag) only exists in newer jax; older releases ship it as
-    ``jax.experimental.shard_map`` with the flag named ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 def _axis_size(mesh: Mesh, names: tuple[str, ...]) -> int:
     size = 1
     for n in names:

@@ -4,8 +4,11 @@ Decode is memory-bound: the whole KV cache streams HBM -> VMEM once per step
 while compute is O(T·hd) per head. The kernel therefore tiles only the KV
 sequence: grid = (batch, q_heads, num_kv_blocks), innermost axis reducing
 with the same online-softmax VMEM scratch as the prefill kernel. A validity
-mask (B, T) expresses both full-cache (`pos <= t`) and ring-buffer sliding
-window occupancy, so one kernel serves all cache layouts.
+mask (B, 1, T) expresses both full-cache (`pos <= t`) and ring-buffer sliding
+window occupancy, so one kernel serves all cache layouts. The mask keeps a
+unit middle axis so that its block (1, 1, BK) ends in two dims the TPU
+accepts at any batch: 1 equals the array's dim and BK is a multiple of 128
+or the whole T.
 """
 from __future__ import annotations
 
@@ -16,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -36,17 +37,17 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_scr, l_scr, acc_scr, *,
     q = q_ref[0, 0].astype(jnp.float32)                   # (1, hd)
     k = k_ref[0, 0].astype(jnp.float32)                   # (BK, hd)
     v = v_ref[0, 0].astype(jnp.float32)                   # (BK, hd)
-    valid = mask_ref[0] != 0                              # (BK,)
+    valid = mask_ref[0] != 0                              # (1, BK)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale  # (1,BK)
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.where(valid[None, :], jnp.exp(s - m_new), 0.0)
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)
 
     l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
@@ -66,7 +67,7 @@ def decode_attention_bhd(q: jax.Array, k: jax.Array, v: jax.Array,
                          scale: Optional[float] = None,
                          block_k: int = 512,
                          interpret: bool = True) -> jax.Array:
-    """q (B,H,1,hd); k,v (B,K,T,hd); mask (B,T) bool/int. -> (B,H,1,hd)."""
+    """q (B,H,1,hd); k,v (B,K,T,hd); mask (B,1,T) bool/int. -> (B,H,1,hd)."""
     bsz, h, _, hd = q.shape
     _, kv, t, _ = k.shape
     group = h // kv
@@ -87,7 +88,7 @@ def decode_attention_bhd(q: jax.Array, k: jax.Array, v: jax.Array,
                          lambda b, hh, ik, g=group: (b, hh // g, ik, 0)),
             pl.BlockSpec((1, 1, block_k, hd),
                          lambda b, hh, ik, g=group: (b, hh // g, ik, 0)),
-            pl.BlockSpec((1, block_k), lambda b, hh, ik: (b, ik)),
+            pl.BlockSpec((1, 1, block_k), lambda b, hh, ik: (b, 0, ik)),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, hd), lambda b, hh, ik: (b, hh, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, h, 1, hd), q.dtype),
@@ -97,6 +98,6 @@ def decode_attention_bhd(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((1, hd), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(q, k, v, mask)

@@ -1,14 +1,14 @@
 """Jitted public wrappers over the Pallas kernels.
 
 Model code calls these with model-layout tensors ((B, S, H, hd) etc.); the
-wrappers transpose to kernel layout, choose block sizes, and run the kernel
-in interpret mode on CPU (the container target) or compiled on real TPU.
-Set ``REPRO_PALLAS_INTERPRET=0`` to force compiled mode.
+wrappers transpose to kernel layout, choose block sizes, and compile the
+kernel for the TPU. Only on the CPU backend, where the unit tests run, do the
+kernels run in interpret mode; any other backend compiles them, so a run on
+an accelerator never falls back to the interpreter unseen.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -22,19 +22,21 @@ from .ssd_scan import ssd_scan_kernel
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
-def _pick_block(n: int, target: int) -> int:
-    """Largest divisor of n that is <= target (prefer 128-multiples)."""
-    best = 1
-    for cand in range(1, min(n, target) + 1):
+def _pick_block(n: int, target: int, align: int) -> int:
+    """Block extent along a dim of size n: n itself when n <= target, else
+    the largest divisor of n that is <= target and a multiple of ``align``,
+    else n. The TPU accepts a block whose last two dims are each a multiple
+    of the tile (8 sublanes, 128 lanes) or the whole array dim; ``align`` is
+    the tile of the dim the block lands on."""
+    if n <= target:
+        return n
+    for cand in range(target - target % align, 0, -align):
         if n % cand == 0:
-            best = cand
-    return best
+            return cand
+    return n
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",
@@ -47,8 +49,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    bq = _pick_block(qt.shape[2], 128)
-    bk = _pick_block(kt.shape[2], 128)
+    bq = _pick_block(qt.shape[2], 128, 8)
+    bk = _pick_block(kt.shape[2], 128, 8)
     out = flash_attention_bhsd(qt, kt, vt, causal=causal, window=window,
                                softcap=softcap, scale=scale, block_q=bq,
                                block_k=bk, interpret=_interpret())
@@ -60,12 +62,12 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      mask: jax.Array, softcap: Optional[float] = None,
                      scale: Optional[float] = None) -> jax.Array:
     """q (B,1,H,hd); k,v (B,T,K,hd); mask (B,1,T) or (B,T) -> (B,1,H,hd)."""
-    if mask.ndim == 3:
-        mask = mask[:, 0, :]
+    if mask.ndim == 2:
+        mask = mask[:, None, :]
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    bk = _pick_block(kt.shape[2], 512)
+    bk = _pick_block(kt.shape[2], 512, 128)
     out = decode_attention_bhd(qt, kt, vt, mask, softcap=softcap, scale=scale,
                                block_k=bk, interpret=_interpret())
     return out.transpose(0, 2, 1, 3)
@@ -109,7 +111,7 @@ def rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
     for dim in shape[:-1]:
         rows *= dim
     x2 = x.reshape(rows, shape[-1])
-    br = _pick_block(rows, 256)
+    br = _pick_block(rows, 256, 8)
     out = rmsnorm_rows(x2, w, eps=eps, plus_one=plus_one, block_rows=br,
                        interpret=_interpret())
     return out.reshape(shape)

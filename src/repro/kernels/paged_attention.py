@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -123,6 +121,6 @@ def paged_decode_attention_bhd(q: jax.Array,
         out_shape=jax.ShapeDtypeStruct((bsz, h, 1, hd), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(page_table, lengths, q, k_pages, v_pages)

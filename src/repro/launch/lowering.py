@@ -249,10 +249,6 @@ def analyze(lowered, compiled, meta: dict) -> dict:
     from .hlo_cost import analyze_hlo_text
 
     xla_cost = compiled.cost_analysis()
-    if isinstance(xla_cost, (list, tuple)):
-        # jax <= 0.4.x returns [dict] (one per device program); newer
-        # releases return the dict directly
-        xla_cost = xla_cost[0] if xla_cost else {}
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     own = analyze_hlo_text(hlo)

@@ -7,6 +7,7 @@ touch jax device state (smoke tests see 1 CPU device; only dryrun.py forces
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 #: hardware constants (v5e-class chip) used by the roofline analysis
 PEAK_FLOPS_BF16 = 197e12        # FLOP/s per chip
@@ -18,11 +19,13 @@ HBM_BYTES = 16 * 2 ** 30        # per chip
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(*, multi_pod: bool = False):
     """Reduced mesh for CI (8 placeholder devices)."""
     shape = (2, 2, 2) if multi_pod else (2, 4)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -157,7 +157,6 @@ def _flash_sharded(q, k, v, *, causal, window, cap, scale):
         _CTX,
         _axis_size,
         _resolve,
-        shard_map_compat,
     )
 
     mesh, rules = _CTX.mesh, _CTX.rules
@@ -184,7 +183,7 @@ def _flash_sharded(q, k, v, *, causal, window, cap, scale):
         return attend_flash_jnp(ql, kl, vl, causal=causal, window=window,
                                 cap=cap, scale=scale, q_offset=offset)
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, sspec, None, None), P(bspec, None, None, None),
                   P(bspec, None, None, None)),

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed import current_mesh, current_rules, shard_map_compat
+from repro.distributed import current_mesh, current_rules
 from .common import ModelConfig
 
 def _local_moe(cfg: ModelConfig, x, router_w, w_gate, w_up, w_down,
@@ -167,7 +167,7 @@ def moe_block(cfg: ModelConfig, p, x: jax.Array) -> tuple[jax.Array, jax.Array]:
 
     xspec = P(bspec, None, None)
     yspec = P(bspec, out_seq_spec, None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, P(None, None), *w_specs),
         out_specs=(yspec, P()),

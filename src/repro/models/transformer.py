@@ -56,9 +56,9 @@ PS = ParamSpec
 def _attn_specs(cfg: ModelConfig) -> dict:
     d, h, kvh, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.hd
     s = {
-        "wq": PS((d, h, hd), ("embed", "heads", "head_dim")),
-        "wk": PS((d, kvh, hd), ("embed", "kv_heads", "head_dim")),
-        "wv": PS((d, kvh, hd), ("embed", "kv_heads", "head_dim")),
+        "wq": PS((d, h, hd), ("embed", "heads", "head_dim"), fan_in=d),
+        "wk": PS((d, kvh, hd), ("embed", "kv_heads", "head_dim"), fan_in=d),
+        "wv": PS((d, kvh, hd), ("embed", "kv_heads", "head_dim"), fan_in=d),
         "wo": PS((h, hd, d), ("heads", "head_dim", "embed"), fan_in=h * hd),
     }
     if cfg.qk_norm:

@@ -2130,8 +2130,11 @@ class PipelineServer:
                        spec_k: Optional[int] = None) -> np.ndarray:
         """Greedy autoregressive generation through the pipeline.
 
-        prompts (B, S) int32 -> (B, max_new_tokens) int32, token-identical
-        to single-engine ``ServeEngine.generate`` at temperature 0.
+        prompts (B, S) int32 -> (B, max_new_tokens) int32: the same greedy
+        computation as single-engine ``ServeEngine.generate`` at
+        temperature 0. In f32 the tokens are identical; in bf16 on a TPU
+        the convoy width changes the matmul tiling, which can swap
+        near-tied logits, so parity there is judged on logits.
 
         Fault story: the session's per-stage KV caches live on the replicas
         that prefilled it. If any of them dies or drains mid-generation, the

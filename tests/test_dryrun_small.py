@@ -48,8 +48,11 @@ print(json.dumps(results))
 def test_dryrun_reduced_mesh():
     out = subprocess.run(
         [sys.executable, "-c", SCRIPT], capture_output=True, text=True,
-        timeout=900, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-        cwd=".")
+        timeout=900, cwd=".",
+        # the child compiles for the forced CPU devices only: it must never
+        # load the TPU runtime, which a chip's one process may hold
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+             "JAX_PLATFORMS": "cpu"})
     assert out.returncode == 0, out.stderr[-3000:]
     results = json.loads(out.stdout.strip().splitlines()[-1])
     assert results["skip"] == "ok"

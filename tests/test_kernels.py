@@ -71,6 +71,19 @@ def test_flash_attention_noncausal():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("n,target,align,want", [
+    (96, 128, 8, 96),        # fits: the whole dim
+    (256, 128, 8, 128),
+    (1536, 512, 128, 512),
+    (640, 512, 128, 128),    # 512, 384 and 256 do not divide 640
+    (150, 128, 8, 150),      # no multiple of 8 divides 150: the whole dim
+])
+def test_pick_block_keeps_tpu_tiling(n, target, align, want):
+    """A block dim is the whole array dim or a multiple of its tile that
+    divides the dim: the rule the TPU compiler holds the last two to."""
+    assert ops._pick_block(n, target, align) == want
+
+
 # ------------------------------------------------------------ decode attention
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -108,6 +108,24 @@ def test_decode_matches_prefill_dense():
             rtol=2e-3, atol=2e-3)
 
 
+def test_bf16_forward_tracks_f32():
+    """At init, bf16 rounding must not change what the model computes. The
+    q/k/v projections are scaled by d_model: scaled by the head count, the
+    attention scores grew large enough to make softmax one-hot, and bf16
+    moved whole logit rows (a worst gap of 2.8 against an absmax of 5)."""
+    cfg = get_smoke("llama3.2-1b")
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(3), (B, 160), 0,
+                              cfg.vocab_size)
+    with jax.default_matmul_precision("highest"):
+        ref, _ = build_model(cfg).forward(params, toks)
+    bf16 = cfg.with_(param_dtype=jnp.bfloat16, activation_dtype=jnp.bfloat16)
+    low, _ = build_model(bf16).forward(
+        jax.tree.map(lambda a: a.astype(jnp.bfloat16), params), toks)
+    diff = np.abs(np.asarray(low) - np.asarray(ref)).max()
+    assert diff < 0.05 * np.abs(np.asarray(ref)).max(), diff
+
+
 def test_decode_matches_prefill_ssm():
     cfg = get_smoke("mamba2-2.7b").with_(ssm_chunk=4)
     model = build_model(cfg)
