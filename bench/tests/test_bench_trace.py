@@ -1,0 +1,73 @@
+"""Trace reduction: busy time as a union, idle gaps named by what the host
+did, device time per program named by the label probes."""
+import json
+import os
+import sys
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.dirname(HERE))
+
+from lib import trace as TR  # noqa: E402
+
+
+@pytest.fixture
+def events():
+    with open(os.path.join(HERE, "fixtures", "trace_small.json")) as f:
+        return json.load(f)["events"]
+
+
+def test_union_and_gaps():
+    assert TR.union_ns([(0, 10), (5, 20), (30, 40)]) == 30
+    assert TR.gaps([(0, 10), (5, 20), (30, 40)], 0, 50) == [(20, 30),
+                                                            (40, 50)]
+
+
+def test_window_busy_and_idle(events):
+    r = TR.reduce(events)
+    assert r["window_s"] == pytest.approx(1000e-9)
+    # ops in the window: [1000,1300] [1500,1600] [1800,1850] [1950,2000]
+    assert r["busy_s"] == pytest.approx(500e-9)
+    # [1300,1500]: the shortest host event over its middle (1400) is the
+    # send, not the wait around it; [1600,1800] and [1850,1950] have none
+    got = sorted((round(s * 1e9), w) for w, s in r["idle_gaps"])
+    assert got == [(100, "no host event"), (200, "envelope send"),
+                   (200, "no host event")]
+
+
+def test_programs_are_named_by_the_label_probes(events):
+    names = TR.labels(events)
+    # the request's two longest programs in order: prefill, then decode;
+    # the eager pad between them and the broadcast before the convoy are
+    # too short to be either
+    assert names == {"jit__lambda#11": "prefill",
+                     "jit__lambda#12": "decode",
+                     "jit__many#13": "decode"}
+    r = TR.reduce(events)
+    assert r["by_label"]["prefill"] == {"s": pytest.approx(300e-9),
+                                        "calls": 1}
+    assert r["by_label"]["decode"] == {"s": pytest.approx(150e-9),
+                                       "calls": 2}
+    assert r["programs"]["jit_pad"]["label"] is None
+    top = [name for name, _ in r["device_ops"]]
+    assert top[0] == "prefill/jit__lambda#11"
+
+
+def test_no_window_or_no_device_gives_nothing(events):
+    assert TR.reduce([e for e in events if e["name"] != TR.WINDOW]) is None
+    assert TR.reduce([e for e in events
+                      if not e["plane"].startswith("/device")]) is None
+
+
+def test_a_trace_recorded_on_the_cpu_loads(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation(TR.WINDOW):
+            jax.jit(lambda a: a @ a)(jnp.ones((64, 64))).block_until_ready()
+    events = TR.load(str(tmp_path))
+    assert TR.window(events) is not None
+    # the CPU backend has no device plane: nothing to reduce
+    assert TR.reduce(events) is None
+    assert "/host:CPU" in TR.structure(events)
