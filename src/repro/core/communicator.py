@@ -52,6 +52,9 @@ class WorldCommunicator:
         self.pending: dict[str, int] = {}
         self.ops_completed = 0
         self.ops_aborted = 0
+        #: pending poll iterations that found nothing (the busy-wait's cost
+        #: on the shared event loop)
+        self.polls_empty = 0
         self._ops_since_yield = 0
         self._rank_cache: dict[str, tuple[World, int]] = {}
 
@@ -128,6 +131,7 @@ class WorldCommunicator:
                     done, value = self._attempt(world, fn)
                     if done:
                         return await self._finish(value)
+                    self.polls_empty += 1
                     if deadline is not None and time.monotonic() > deadline:
                         raise TimeoutError(
                             f"op on world '{world.name}' timed out after "

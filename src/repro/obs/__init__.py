@@ -8,7 +8,9 @@ cycles. Five pieces:
   :class:`TraceContext` rides every :class:`~repro.serving.envelope.Envelope`
   so one session's lifecycle (prefill, per-step decode, handoff, snapshot,
   migration, heal, restore replay) reconstructs as one causal tree; head
-  sampling with tail-based keep rules bounds its cost at fleet scale;
+  sampling with tail-based keep rules bounds its cost at fleet scale; while
+  a JAX profile records, its open spans are mirrored into the profile as
+  ``mw.*`` annotations on the device trace's clock;
 * :mod:`~repro.obs.sketch` — :class:`LogSketch`, a DDSketch-style
   mergeable quantile sketch with a guaranteed relative-error bound, the
   primitive that makes tail latencies (p95 TTFT, p99 decode) foldable
@@ -31,7 +33,7 @@ from .recorder import FlightRecorder, validate_dump
 from .sketch import LogSketch
 from .slo import (BurnRatePolicy, DEFAULT_BURN_POLICIES, SLOMonitor,
                   SLOSpec, SLOTracker)
-from .trace import (DEFAULT_KEEP_KINDS, SpanKind, TraceContext, Tracer,
+from .trace import (DEFAULT_KEEP_KINDS, Span, TraceContext, Tracer,
                     connected_tree)
 
 __all__ = [
@@ -43,7 +45,7 @@ __all__ = [
     "SLOMonitor",
     "SLOSpec",
     "SLOTracker",
-    "SpanKind",
+    "Span",
     "StageDigest",
     "TraceContext",
     "Tracer",

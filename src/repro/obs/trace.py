@@ -32,13 +32,14 @@ root span closes. Tracing cost therefore stays ~flat as sessions grow:
 the ring holds every anomalous trace plus a ``sample_rate`` slice of the
 healthy ones.
 
-Span taxonomy (the ``kind`` strings the summary aggregates over):
+Ring span taxonomy (the ``kind`` strings the summary aggregates over):
 
 ======================  ====================================================
 ``session``             client root — one per ``generate()`` call
 ``prefill``             stage-side prefill dispatch (KV-cache build)
 ``ttft``                client-observed prefill round trip (first token)
-``decode``              one stage-side decode step (possibly fused/batched)
+``decode``              one session's stage-side decode step, from its
+                        arrival in the replica's inbox to its forward
 ``decode_step``         client-observed per-token round trip
 ``handoff``             prefill→decode pool KV streaming + install
 ``snapshot``            one background snapshot write (base or delta)
@@ -49,6 +50,32 @@ Span taxonomy (the ``kind`` strings the summary aggregates over):
 ``bootstrap``           warm scale-up (weight fetch + compile warmup)
 ``heal``                controller heal of one failed replica
 ======================  ====================================================
+
+Device-clock mirror. While a JAX profiler session records, every span
+opened with :meth:`Tracer.open` is also a ``jax.profiler.TraceAnnotation``
+named ``mw.<layer>.<what>``, so the profile holds the program's host path
+on the host plane beside the device's operations, on one clock. Its stats
+carry ``trace_id``, ``span_id``, ``parent_id``, ``stage``, ``worker`` and,
+for executor calls and their dispatches, ``width`` (the sessions in the
+call). With no profile recording the mirror costs one ``is_enabled()``
+check a span. The layer spans go to the mirror and to counters kept by the
+code that does the work, not into the ring:
+
+========================  ==================================================
+``mw.client.session``     ``generate()`` entry → return (ring ``session``)
+``mw.client.step``        envelope sent → response in hand (ring ``ttft``,
+                          ``decode_step`` or ``verify_step``)
+``mw.client.token``       response in hand → token appended: the logits'
+                          copy to the host (which waits for the last
+                          stage's program), margin, argmax
+``mw.replica.queue``      envelope put in a replica's inbox → taken by a
+                          handler (every envelope, convoy mates too)
+``mw.replica.gather``     decode handler start → convoy submitted
+``mw.replica.dispatch``   executor call submitted → coroutine resumed
+``mw.exec.<call>``        the executor call on its worker thread
+                          (``prefill``, ``decode``, ``decode_many``, ...)
+``mw.replica.forward``    result sent on to the next stage or the client
+========================  ==================================================
 """
 from __future__ import annotations
 
@@ -58,7 +85,9 @@ import time
 from collections import OrderedDict, deque
 from typing import Iterable, Optional
 
-__all__ = ["SpanKind", "TraceContext", "Tracer", "connected_tree",
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Span", "TraceContext", "Tracer", "connected_tree",
            "DEFAULT_KEEP_KINDS"]
 
 #: span kinds that always promote an unsampled trace to the ring — the
@@ -66,24 +95,6 @@ __all__ = ["SpanKind", "TraceContext", "Tracer", "connected_tree",
 DEFAULT_KEEP_KINDS = frozenset({
     "heal", "migrate", "restore", "restore_replay", "reprefill",
 })
-
-
-class SpanKind:
-    """Well-known span kind strings (any string is accepted)."""
-
-    SESSION = "session"
-    PREFILL = "prefill"
-    TTFT = "ttft"
-    DECODE = "decode"
-    DECODE_STEP = "decode_step"
-    HANDOFF = "handoff"
-    SNAPSHOT = "snapshot"
-    MIGRATE = "migrate"
-    RESTORE = "restore"
-    RESTORE_REPLAY = "restore_replay"
-    REPREFILL = "reprefill"
-    BOOTSTRAP = "bootstrap"
-    HEAL = "heal"
 
 
 class TraceContext:
@@ -110,6 +121,23 @@ class TraceContext:
         return (f"TraceContext(trace={self.trace_id}, span={self.span_id}, "
                 f"parent={self.parent_id}"
                 + ("" if self.sampled else ", unsampled") + ")")
+
+
+class Span:
+    """One span opened by :meth:`Tracer.open` and ended by
+    :meth:`Tracer.close`. ``t0`` is its monotonic start; ``ctx`` is its own
+    context (the parent of its children), or None where it has none: a
+    disabled tracer, or a layer span with no traced parent."""
+
+    __slots__ = ("t0", "ctx", "kind", "worker", "_ann")
+
+    def __init__(self, t0: float, ctx: Optional[TraceContext], kind: str,
+                 worker: str, ann: Optional[TraceAnnotation]):
+        self.t0 = t0
+        self.ctx = ctx
+        self.kind = kind
+        self.worker = worker
+        self._ann = ann
 
 
 # ring slot field offsets (one preallocated list per slot, mutated in place)
@@ -256,6 +284,47 @@ class Tracer:
         while len(self._resolved_order) > 4096:
             self._resolved.pop(self._resolved_order.popleft(), None)
 
+    # ------------------------------------------------------- open / close
+    def open(self, name: str, parent: Optional[TraceContext] = None, *,
+             kind: str = "", worker: str = "", stage: int = -1,
+             width: int = 0) -> Span:
+        """Start a span now, where the work starts. While a JAX profile
+        records it is also the annotation ``name`` (``mw.<layer>.<what>``)
+        on the profile's clock; with ``kind`` it goes into the ring at
+        :meth:`close`, as a child of ``parent`` (a root when ``parent`` is
+        None). The returned span always carries its start, so callers keep
+        their counters from :meth:`close` whether tracing is on or not.
+        Safe from worker threads when ``kind`` is empty."""
+        t0 = time.monotonic()
+        if not self.enabled:
+            return Span(t0, None, "", worker, None)
+        mirror = TraceAnnotation.is_enabled()
+        ctx = None
+        if kind or (mirror and parent is not None):
+            ctx = self.begin(parent)
+        ann = None
+        if mirror:
+            stats = {"stage": stage, "worker": worker}
+            if ctx is not None:
+                stats.update(trace_id=ctx.trace_id, span_id=ctx.span_id,
+                             parent_id=ctx.parent_id)
+            if width:
+                stats["width"] = width
+            ann = TraceAnnotation(name, **stats)
+            ann.__enter__()
+        return Span(t0, ctx, kind, worker, ann)
+
+    def close(self, span: Span, detail: str = "") -> float:
+        """End ``span`` where the work ends; returns its seconds. Records
+        it into the ring when it was opened with a ``kind``."""
+        dt = time.monotonic() - span.t0
+        if span._ann is not None:
+            span._ann.__exit__(None, None, None)
+            span._ann = None
+        if span.kind:
+            self.record(span.ctx, span.kind, span.t0, dt, span.worker, detail)
+        return dt
+
     def span(self, parent: Optional[TraceContext], kind: str, t0: float,
              worker: str = "", detail: str = "") -> Optional[TraceContext]:
         """Mint a child of ``parent`` and record it closed at now-t0 in one
@@ -289,12 +358,6 @@ class Tracer:
                 "detail": s[_DETAIL],
             })
         return out
-
-    def trace_ids(self) -> list[int]:
-        seen: dict[int, None] = {}
-        for s in self._live_slots():
-            seen.setdefault(s[_TRACE])
-        return list(seen)
 
     def summary(self) -> dict:
         """Per-kind latency digests over the live ring:

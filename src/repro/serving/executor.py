@@ -53,6 +53,13 @@ from .partition import (
 )
 
 
+def _named_jit(name: str, fn):
+    """``jax.jit(fn)`` under ``name``, which XLA takes for the program's
+    module name: the device trace then says which stage program ran."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
 class StageExecutor:
     def __init__(self, cfg: ModelConfig, spec: StageSpec, sparams: Any, *,
                  max_len: int = 256, pad_seq: bool = True,
@@ -96,13 +103,18 @@ class StageExecutor:
         #: distinct cache leaf signature (built once, reused every pad)
         self._pad_caches: dict = {}
         tokens_in = spec.first
+        #: program name prefix: the stage, e.g. ``s1_decode_many``
+        self._tag = f"s{spec.index}"
 
-        self._score = jax.jit(
+        self._score = _named_jit(
+            f"{self._tag}_score",
             lambda sp, x: stage_forward(cfg, spec, sp, x, tokens_in=tokens_in))
-        self._prefill = jax.jit(
+        self._prefill = _named_jit(
+            f"{self._tag}_prefill",
             lambda sp, x: stage_prefill(cfg, spec, sp, x, max_len,
                                         tokens_in=tokens_in))
-        self._decode = jax.jit(
+        self._decode = _named_jit(
+            f"{self._tag}_decode",
             lambda sp, c, x, t: stage_decode(cfg, spec, sp, c, x, t,
                                              tokens_in=tokens_in))
         # N sessions, each with its own cache and position, in one dispatch:
@@ -123,7 +135,7 @@ class StageExecutor:
                     tuple(jax.tree.map(lambda l: l[i], new_stacked)
                           for i in range(n)))
 
-        self._decode_many = jax.jit(_many)
+        self._decode_many = _named_jit(f"{self._tag}_decode_many", _many)
 
         # Speculative verification: K stacked tokens per session (the
         # current token plus k draft proposals) integrated in ONE dispatch.
@@ -162,7 +174,8 @@ class StageExecutor:
                     tuple(jax.tree.map(lambda l: l[i], new_stacked)
                           for i in range(n)))
 
-        self._verify_many_fn = jax.jit(_vmany)
+        self._verify_many_fn = _named_jit(f"{self._tag}_verify_many",
+                                          _vmany)
         self._paged_verify = None
         #: jitted draft rollouts, one per proposal budget k (the greedy
         #: argmax feedback loop makes k part of the program, not a shape)
@@ -345,7 +358,7 @@ class StageExecutor:
                     jnp.argmax(y, axis=-1).astype(jnp.int32)[:, None])
             return jnp.concatenate(props, axis=1), c
 
-        return jax.jit(_roll)
+        return _named_jit(f"{self._tag}_propose", _roll)
 
     def propose_rollout(self, cache: Any, xs: jax.Array, t, k: int
                         ) -> tuple[jax.Array, Any]:
@@ -588,7 +601,8 @@ class StageExecutor:
                     for leaf, pg in zip(pool_leaves, pgs))
                 return outs, new_pool
 
-            self._paged_verify = jax.jit(_many_pv)
+            self._paged_verify = _named_jit(f"{self._tag}_verify_paged",
+                                            _many_pv)
         return self._paged_verify
 
     # ------------------------------------------------------------ paged mode
@@ -728,7 +742,8 @@ class StageExecutor:
                     for leaf, pg in zip(pool_leaves, pgs))
                 return outs, new_pool
 
-            self._paged_many = jax.jit(_many_paged)
+            self._paged_many = _named_jit(f"{self._tag}_decode_paged",
+                                          _many_paged)
         return self._paged_many
 
     # ---------------------------------------------------------- warm profile

@@ -101,7 +101,7 @@ from repro.core import (
     WorldSpec,
 )
 from repro.core.online import OnlineInstantiator
-from repro.obs import FlightRecorder, LogSketch, Tracer
+from repro.obs import FlightRecorder, LogSketch, Span, Tracer
 from repro.statexfer import (
     INT8,
     MigrationManager,
@@ -217,6 +217,14 @@ class _Replica:
         self.tokens_out = 0          # decode tokens produced (B per step)
         self.decode_batches = 0      # fused decode dispatches
         self.decode_steps = 0        # decode envelopes served
+        # -- decode host path, seconds summed over the ``decode_steps`` and
+        #    ``decode_batches`` above: each envelope's inbox arrival to its
+        #    convoy's submit (queue + gather); each convoy's executor call
+        #    submit to the coroutine's resume, and that call's own time on
+        #    its worker thread (the rest is the thread hop) --------------
+        self.decode_wait_s_sum = 0.0
+        self.dispatch_s_sum = 0.0
+        self.exec_s_sum = 0.0
         self.retries_sent = 0        # sessions bounced back for re-prefill
         self.expired = 0             # envelopes dropped past their deadline
         # -- per-kind latency split (MetricsHub turns the deltas into TTFT
@@ -242,6 +250,9 @@ class _Replica:
         self._credits: dict[str, float] = {}
         #: tenant -> decode steps served (the fairness test's ground truth)
         self.tenant_served: dict[str, int] = {}
+        #: id(envelope) -> its open ``mw.replica.queue`` span, from the
+        #: inbox put to the handler that takes it
+        self._queue_spans: dict[int, Span] = {}
 
     def queue_depth(self) -> int:
         return (self.inbox.qsize() + len(self._stash) + self.inflight
@@ -311,12 +322,37 @@ class _Replica:
 
     async def _pump(self, world: str) -> None:
         comm = self.worker.comm
+        tracer = self.server.tracer
         try:
             while True:
                 payload = await comm.recv(0, world)
+                self._queue_spans[id(payload)] = tracer.open(
+                    "mw.replica.queue", payload.trace,
+                    worker=self.worker_id, stage=self.stage)
                 await self.inbox.put((payload, time.monotonic()))
         except (WorldBrokenError, WorldNotFoundError, asyncio.CancelledError):
             return
+
+    def _taken(self, env: Envelope) -> None:
+        """A handler has ``env``: close its ``mw.replica.queue`` span."""
+        span = self._queue_spans.pop(id(env), None)
+        if span is not None:
+            self.server.tracer.close(span)
+
+    def _exec(self, name: str, width: int, fn, *args):
+        """Run one executor call on its worker thread inside the span
+        ``name`` (``mw.exec.<call>``); a decode call's host seconds go to
+        ``exec_s_sum``. One call at a time per replica: its serve loop
+        awaits each."""
+        tracer = self.server.tracer
+        span = tracer.open(name, worker=self.worker_id, stage=self.stage,
+                           width=width)
+        try:
+            return fn(*args)
+        finally:
+            dt = tracer.close(span)
+            if name.startswith("mw.exec.decode"):
+                self.exec_s_sum += dt
 
     # ------------------------------------------------------------- serve loop
     async def run(self) -> None:
@@ -327,11 +363,12 @@ class _Replica:
                 env, t_enq = self._stash.popleft()
             else:
                 env, t_enq = await self.inbox.get()
+            self._taken(env)
             t0 = time.monotonic()
             self.wait_s_sum += t0 - t_enq
             self.inflight += 1
             try:
-                await self._dispatch(ex, loop, env, t0)
+                await self._dispatch(ex, loop, env, t0, t_enq)
             except asyncio.CancelledError:
                 raise
             except (WorldBrokenError, WorldNotFoundError):
@@ -355,7 +392,7 @@ class _Replica:
             self._maybe_reap(t0)
 
     async def _dispatch(self, ex: StageExecutor, loop, env: Envelope,
-                        t0: float) -> None:
+                        t0: float, t_enq: float) -> None:
         sid = env.session_id
         if env.kind in (Kind.DECODE, Kind.FINISH, Kind.VERIFY):
             target = self.migrated.get(sid)
@@ -407,15 +444,23 @@ class _Replica:
         elif kind is Kind.VERIFY:
             await self._handle_verify(ex, loop, env, t0)
         else:
-            await self._handle_decode(ex, loop, env, t0)
+            await self._handle_decode(ex, loop, env, t0, t_enq)
 
     async def _handle_prefill(self, ex: StageExecutor, loop, env: Envelope,
                               t0: float) -> None:
         if self.draining:
             await self._send_retry(env)
             return
-        y, cache = await loop.run_in_executor(None, ex.prefill, env.payload)
         server = self.server
+        tracer = server.tracer
+        span = tracer.open("mw.replica.dispatch", env.trace,
+                           worker=self.worker_id, stage=self.stage, width=1)
+        try:
+            y, cache = await loop.run_in_executor(
+                None, self._exec, "mw.exec.prefill", 1, ex.prefill,
+                env.payload)
+        finally:
+            tracer.close(span)
         if server._is_last(self.stage):
             y = y[:, -1]              # client only needs last-position logits
         sid = env.session_id
@@ -483,13 +528,14 @@ class _Replica:
         server.tracer.span(env.trace, "prefill", t0, self.worker_id)
 
     async def _handle_decode(self, ex: StageExecutor, loop, env: Envelope,
-                             t0: float) -> None:
+                             t0: float, t_enq: float) -> None:
         """Continuous-batching micro-scheduler: serve this decode step fused
         with every compatible queued step (same per-session batch shape and
         model, any position), waiting up to ``microbatch_wait_s`` for
         stragglers when more sessions are open than are in hand. Batch
         slots are arbitrated across tenants by weighted deficit round-robin
-        (see :meth:`_pull_compatible`)."""
+        (see :meth:`_pull_compatible`). ``t_enq`` is the step's arrival in
+        the inbox."""
         sess0 = self.sessions.get(env.session_id)
         if self.draining or sess0 is None:
             self.drop_session(env.session_id)
@@ -499,18 +545,25 @@ class _Replica:
         # replayed or untagged step must never run foreign weights
         ex = self.executor_for(sess0.model)
         batch: list[Envelope] = [env]
+        #: session id -> its step's arrival in the inbox
+        arrived = {env.session_id: t_enq}
         self.active.add(env.session_id)
         max_n = self.server.microbatch_max
         deadline = t0 + self.server.microbatch_wait_s
+        tr = self.server.tracer
         try:
+            gather = tr.open("mw.replica.gather", env.trace,
+                             worker=self.worker_id, stage=self.stage)
             while len(batch) < max_n:
-                pulled = self._pull_compatible(env, max_n - len(batch), batch)
+                pulled = self._pull_compatible(env, max_n - len(batch), batch,
+                                               arrived)
                 if pulled:
                     continue
                 if (len(self.sessions) <= len(batch)
                         or time.monotonic() >= deadline):
                     break
                 await asyncio.sleep(0)
+            tr.close(gather)
 
             # a concurrent teardown/reap may have dropped a session between
             # the compatibility check and now — bounce those, fuse the rest
@@ -523,9 +576,17 @@ class _Replica:
                     live.append((e, sess))
             if not live:
                 return
+            n = len(live)
+            t_sub = time.monotonic()
+            for e, _ in live:
+                self.decode_wait_s_sum += t_sub - arrived[e.session_id]
+            span = tr.open("mw.replica.dispatch", env.trace,
+                           worker=self.worker_id, stage=self.stage, width=n)
             try:
                 outs = await loop.run_in_executor(
-                    None, ex.decode_many,
+                    None, self._exec,
+                    "mw.exec.decode_many" if n > 1 else "mw.exec.decode",
+                    n, ex.decode_many,
                     [s.cache for _, s in live],
                     [e.payload for e, _ in live],
                     [e.step for e, _ in live])
@@ -537,9 +598,11 @@ class _Replica:
                     self.drop_session(e.session_id)
                     await self._send_retry(e)
                 return
+            finally:
+                dt = tr.close(span)
             now = time.monotonic()
+            self.dispatch_s_sum += dt
             self.decode_batches += 1
-            tr = self.server.tracer
             for (e, sess), (y, new_cache) in zip(live, outs):
                 sess.cache = new_cache
                 sess.step = e.step
@@ -549,8 +612,9 @@ class _Replica:
                 t_name = e.tenant or "default"
                 self.tenant_served[t_name] = (
                     self.tenant_served.get(t_name, 0) + 1)
-                tr.span(e.trace, "decode", t0, self.worker_id)
                 await self._forward_pinned(dataclasses.replace(e, payload=y))
+                tr.span(e.trace, "decode", arrived[e.session_id],
+                        self.worker_id)
                 self.processed += 1
             dt = time.monotonic() - t0
             self.service_s_sum += dt
@@ -730,10 +794,12 @@ class _Replica:
                 self.active.discard(e.session_id)
 
     def _pull_compatible(self, proto: Envelope, n: int,
-                         batch: list[Envelope]) -> int:
+                         batch: list[Envelope],
+                         arrived: Optional[dict] = None) -> int:
         """Drain queued envelopes: coalesce compatible DECODEs into ``batch``
         (counting them in-flight so drain can't observe a false empty),
-        stash everything else in arrival order.
+        stash everything else in arrival order. ``arrived``, when given,
+        gets each pulled step's inbox arrival by session id.
 
         Multi-tenant arbitration (weighted deficit round-robin): when more
         compatible steps are queued than batch slots remain, the slots are
@@ -792,7 +858,10 @@ class _Replica:
                 self._stash.append((env, t_enq))
                 continue
             self._credits[pick] = self._credits.get(pick, 0.0) - 1.0
+            self._taken(env)
             self.wait_s_sum += time.monotonic() - t_enq
+            if arrived is not None:
+                arrived[env.session_id] = t_enq
             self.inflight += 1
             batch.append(env)
             in_batch.add(env.session_id)
@@ -816,36 +885,42 @@ class _Replica:
         fwd = env.kind in (Kind.PREFILL, Kind.SCORE)
         role = env.role if fwd else None
         model = env.model if fwd else None
-        while True:
-            if env.expired(time.monotonic()):
-                self.expired += 1
-                return None
-            world = self.router.try_pick(
-                least_loaded=self.server.least_loaded, role=role,
-                model=model)
-            if world is None:
-                # Every routable downstream world is gone. Dying here would
-                # drop the in-flight payload and kill this serve loop for
-                # good — park instead and retry once the controller
-                # adds/heals a downstream replica.
-                self.parked += 1
-                if ((role is not None or model is not None)
-                        and self.router.healthy()):
-                    # worlds exist, just none role/model-capable: the
-                    # controller is growing that pool (or a load/swap is in
-                    # flight) — the any-world event is already set, so poll
-                    # instead of waiting on it
-                    await asyncio.sleep(0.005)
-                else:
-                    await self.router.wait_healthy()
-                continue
-            try:
-                await comm.send(env, 1, world)
-                return world
-            except WorldBrokenError:
-                self.router.mark_broken(world)
-            except WorldNotFoundError:
-                self.router.remove(world)
+        span = self.server.tracer.open("mw.replica.forward", env.trace,
+                                       worker=self.worker_id,
+                                       stage=self.stage)
+        try:
+            while True:
+                if env.expired(time.monotonic()):
+                    self.expired += 1
+                    return None
+                world = self.router.try_pick(
+                    least_loaded=self.server.least_loaded, role=role,
+                    model=model)
+                if world is None:
+                    # Every routable downstream world is gone. Dying here
+                    # would drop the in-flight payload and kill this serve
+                    # loop for good — park instead and retry once the
+                    # controller adds/heals a downstream replica.
+                    self.parked += 1
+                    if ((role is not None or model is not None)
+                            and self.router.healthy()):
+                        # worlds exist, just none role/model-capable: the
+                        # controller is growing that pool (or a load/swap is
+                        # in flight) — the any-world event is already set, so
+                        # poll instead of waiting on it
+                        await asyncio.sleep(0.005)
+                    else:
+                        await self.router.wait_healthy()
+                    continue
+                try:
+                    await comm.send(env, 1, world)
+                    return world
+                except WorldBrokenError:
+                    self.router.mark_broken(world)
+                except WorldNotFoundError:
+                    self.router.remove(world)
+        finally:
+            self.server.tracer.close(span)
 
     async def _forward_pinned(self, env: Envelope) -> None:
         """Send a decode result along the session's pinned route; if the pin
@@ -860,14 +935,19 @@ class _Replica:
         if world is None:
             await self._send_retry(env)
             return
+        span = self.server.tracer.open("mw.replica.forward", env.trace,
+                                       worker=self.worker_id,
+                                       stage=self.stage)
         try:
             await self.worker.comm.send(env, 1, world)
+            return
         except WorldBrokenError:
             self.router.mark_broken(world)
-            await self._send_retry(env)
         except WorldNotFoundError:
             self.router.remove(world)
-            await self._send_retry(env)
+        finally:
+            self.server.tracer.close(span)
+        await self._send_retry(env)
 
     async def _expire(self, env: Envelope) -> None:
         """Deadline enforcement at the stage boundary: the client has given
@@ -1154,6 +1234,9 @@ class PipelineServer:
         #: the TTFT / per-token-decode EWMAs the per-role policies consume
         self.ttft_log: list[float] = []
         self.decode_lat_log: list[float] = []
+        #: seconds from a response in hand to its tokens appended, summed
+        #: (the logits' copy to the host waits for the last stage's program)
+        self.token_host_s_sum = 0.0
         self._wired_managers: set[str] = set()
         self._wire_manager(self.client.manager, self.client_router)
 
@@ -2177,12 +2260,13 @@ class PipelineServer:
         # the *client* owns the session's root span: a re-prefill changes
         # the session id but not the trace, so RETRY bounces, restores, and
         # the resumed decode all reconstruct under one tree
-        root = tracer.begin()
-        t_root = time.monotonic()
-        #: last client span ctx sent but not yet recorded — the failure
+        session = tracer.open("mw.client.session", kind="session",
+                              worker=CLIENT)
+        root = session.ctx
+        #: last client step span sent but not yet closed — the failure
         #: handler closes it, so a stage-side child span never outlives an
         #: unrecorded parent (timeouts would otherwise orphan the subtree)
-        pending = None
+        pending: Optional[Span] = None
         while len(out) < max_new_tokens:
             try:
                 if sid is None:
@@ -2201,29 +2285,25 @@ class PipelineServer:
                         self.session_models[sid] = model
                     if tenant is not None:
                         self.session_tenants[sid] = tenant
-                    t_send = time.monotonic()
-                    ctx = tracer.begin(root)
-                    pending = ("ttft", ctx, t_send)
+                    pending = tracer.open("mw.client.step", root,
+                                          kind="ttft", worker=CLIENT)
                     env = Envelope(
                         next(self._req_ids), sid, Kind.PREFILL,
                         step=hist_len - 1,
                         deadline=time.monotonic() + step_timeout,
-                        payload=hist, role=ROLE_PREFILL, trace=ctx,
+                        payload=hist, role=ROLE_PREFILL, trace=pending.ctx,
                         model=model, tenant=tenant)
                     resp = await self._roundtrip(env, world, step_timeout)
                     if resp.kind is Kind.RETRY:
-                        tracer.record(ctx, "ttft", t_send,
-                                      time.monotonic() - t_send, CLIENT,
-                                      "retry")
+                        tracer.close(pending, "retry")
                         pending = None
                         raise _SessionLost("prefill bounced")
                     if resp.kind is Kind.FINISH:
                         raise _SessionLost(resp.error or "server finished")
-                    dt = time.monotonic() - t_send
+                    dt = tracer.close(pending)
+                    pending = None
                     self._note_latency(self.ttft_log, dt)
                     self._note_tenant(tenant, "ttft", dt)
-                    tracer.record(ctx, "ttft", t_send, dt, CLIENT)
-                    pending = None
                     if self.client_router.pinned(sid) is None:
                         # a split stage-0 already stitched the pin onto the
                         # session's decode home during the prefill pass —
@@ -2249,9 +2329,9 @@ class PipelineServer:
                             # path below; the next round re-picks a draft
                             self.spec_fallbacks_total += 1
                     if props is not None:
-                        t_send = time.monotonic()
-                        ctx = tracer.begin(root)
-                        pending = ("verify_step", ctx, t_send)
+                        pending = tracer.open("mw.client.step", root,
+                                              kind="verify_step",
+                                              worker=CLIENT)
                         payload = np.concatenate(
                             [np.asarray(out[-1])[:, None], props],
                             axis=1).astype(np.int32)
@@ -2260,25 +2340,23 @@ class PipelineServer:
                             step=hist_len + (len(out) - base) - 1,
                             deadline=time.monotonic() + step_timeout,
                             payload=jnp.asarray(payload), spec_k=k_round,
-                            role=ROLE_DECODE, trace=ctx, model=model,
+                            role=ROLE_DECODE, trace=pending.ctx, model=model,
                             tenant=tenant)
                         resp = await self._roundtrip(env, world,
                                                      step_timeout)
                         if resp.kind is Kind.RETRY:
-                            tracer.record(ctx, "verify_step", t_send,
-                                          time.monotonic() - t_send,
-                                          CLIENT, "retry")
+                            tracer.close(pending, "retry")
                             pending = None
                             raise _SessionLost("verify bounced")
                         if resp.kind is Kind.FINISH:
                             raise _SessionLost(
                                 resp.error or "server finished")
-                        dt = time.monotonic() - t_send
+                        dt = tracer.close(pending)
+                        pending = None
                         self._note_latency(self.decode_lat_log, dt)
                         self._note_tenant(tenant, "decode", dt)
-                        tracer.record(ctx, "verify_step", t_send, dt,
-                                      CLIENT)
-                        pending = None
+                        token = tracer.open("mw.client.token", root,
+                                            worker=CLIENT)
                         # (B, m+1) accepted prefix + bonus token — every
                         # column is the target model's own greedy argmax,
                         # so appending the whole block preserves parity
@@ -2295,35 +2373,34 @@ class PipelineServer:
                                     + bsz)
                             if token_times is not None:
                                 token_times.append(t_now)
+                        self.token_host_s_sum += tracer.close(token)
                         continue
                     # position of the fed token: history end + tokens
                     # generated since that history was prefilled
-                    t_send = time.monotonic()
-                    ctx = tracer.begin(root)
-                    pending = ("decode_step", ctx, t_send)
+                    pending = tracer.open("mw.client.step", root,
+                                          kind="decode_step", worker=CLIENT)
                     env = Envelope(
                         next(self._req_ids), sid, Kind.DECODE,
                         step=hist_len + (len(out) - base) - 1,
                         deadline=time.monotonic() + step_timeout,
                         payload=out[-1][:, None], role=ROLE_DECODE,
-                        trace=ctx, model=model, tenant=tenant)
+                        trace=pending.ctx, model=model, tenant=tenant)
                     resp = await self._roundtrip(env, world, step_timeout)
                     if resp.kind is Kind.RETRY:
-                        tracer.record(ctx, "decode_step", t_send,
-                                      time.monotonic() - t_send, CLIENT,
-                                      "retry")
+                        tracer.close(pending, "retry")
                         pending = None
                         raise _SessionLost("decode bounced")
                     if resp.kind is Kind.FINISH:
                         raise _SessionLost(resp.error or "server finished")
-                    dt = time.monotonic() - t_send
+                    dt = tracer.close(pending)
+                    pending = None
                     self._note_latency(self.decode_lat_log, dt)
                     self._note_tenant(tenant, "decode", dt)
-                    tracer.record(ctx, "decode_step", t_send, dt, CLIENT)
-                    pending = None
                 # greedy pick on the host: the logits are tiny (B,V) and a
                 # jax dispatch per token per session would dominate the
-                # client loop at smoke scale
+                # client loop at smoke scale. The copy waits for the last
+                # stage's program, so the token span holds that wait too.
+                token = tracer.open("mw.client.token", root, worker=CLIENT)
                 logits = np.asarray(resp.payload)
                 self._note_margin(sid, logits)
                 tok = np.argmax(logits, axis=-1).astype(np.int32)
@@ -2333,19 +2410,18 @@ class PipelineServer:
                         self.tenant_tokens.get(tenant, 0) + bsz)
                 if token_times is not None:
                     token_times.append(time.monotonic())
+                self.token_host_s_sum += tracer.close(token)
             except (_SessionLost, asyncio.TimeoutError,
                     WorldBrokenError, WorldNotFoundError) as e:
                 if pending is not None:
                     # the step died without a response; close its span so
                     # any stage-side child recorded before the failure
                     # still parents back into the tree
-                    p_kind, p_ctx, p_t = pending
-                    tracer.record(p_ctx, p_kind, p_t,
-                                  time.monotonic() - p_t, CLIENT,
-                                  f"error={type(e).__name__}")
+                    tracer.close(pending, f"error={type(e).__name__}")
                     pending = None
                 restarts += 1
                 if restarts > max_restarts:
+                    tracer.close(session, f"error={type(e).__name__}")
                     raise RuntimeError(
                         f"generation failed after {max_restarts} session "
                         f"restarts: {e}") from e
@@ -2383,8 +2459,7 @@ class PipelineServer:
             self.session_margins.pop(sid, None)
             self.session_models.pop(sid, None)
             self.session_tenants.pop(sid, None)
-        tracer.record(root, "session", t_root, time.monotonic() - t_root,
-                      CLIENT, f"tokens={len(out)} restarts={restarts}")
+        tracer.close(session, f"tokens={len(out)} restarts={restarts}")
         return np.stack([np.asarray(t) for t in out], axis=1)
 
     # ------------------------------------------------------------------ intro
@@ -2440,6 +2515,10 @@ class PipelineServer:
                     "open_sessions": rep.open_sessions(),
                     "decode_batches": rep.decode_batches,
                     "decode_steps": rep.decode_steps,
+                    "decode_wait_s_sum": rep.decode_wait_s_sum,
+                    "dispatch_s_sum": rep.dispatch_s_sum,
+                    "exec_s_sum": rep.exec_s_sum,
+                    "polls_empty": rep.worker.comm.polls_empty,
                     "retries_sent": rep.retries_sent,
                     "expired": rep.expired,
                     "held_sessions": len(rep.held),
@@ -2454,3 +2533,10 @@ class PipelineServer:
                     "spec_proposals": rep.spec_proposals,
                 }
         return out
+
+    def client_stats(self) -> dict[str, float]:
+        """The client's host-path counters, cumulative: ``token_host_s_sum``
+        and the empty polls of its communicator (``replica_stats`` has each
+        replica's)."""
+        return {"token_host_s_sum": self.token_host_s_sum,
+                "polls_empty": self.client.comm.polls_empty}

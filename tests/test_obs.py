@@ -95,6 +95,99 @@ def test_tracer_disabled_and_orphan_guard():
     assert on.recorded == 0
 
 
+def _mirrored(log_dir) -> list[dict]:
+    """The ``mw.*`` host events of the profile written under ``log_dir``."""
+    import glob
+    import warnings
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("mw.", "outer")):
+                    with warnings.catch_warnings():
+                        # the binding's stats type warns on introspection
+                        warnings.simplefilter("ignore", DeprecationWarning)
+                        stats = dict(e.stats)
+                    out.append({"name": e.name, "t0": e.start_ns,
+                                "t1": e.start_ns + e.duration_ns,
+                                "stats": stats})
+    return sorted(out, key=lambda e: e["t0"])
+
+
+def test_tracer_mirrors_open_spans_into_the_profile(tmp_path):
+    """Spans held open across the awaits of interleaved coroutines come
+    out of a profile with their names, stats and times, inside the
+    annotation around them; the layer spans stay out of the ring."""
+    tr = Tracer()
+
+    async def step(i, root):
+        s = tr.open("mw.client.step", root, kind="decode_step",
+                    worker="client")
+        await asyncio.sleep(0.002 * (3 - i))
+        q = tr.open("mw.replica.queue", s.ctx, worker=f"w{i}", stage=i)
+        await asyncio.sleep(0.001)
+        tr.close(q)
+        tr.close(s)
+
+    async def main():
+        session = tr.open("mw.client.session", kind="session",
+                          worker="client")
+        await asyncio.gather(*(step(i, session.ctx) for i in range(3)))
+        await asyncio.get_running_loop().run_in_executor(
+            None, lambda: tr.close(tr.open("mw.exec.decode_many", stage=1,
+                                           worker="w1", width=3)))
+        tr.close(session)
+        return session.ctx
+
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("outer"):
+            root = asyncio.run(main())
+    ev = _mirrored(tmp_path)
+    outer = [e for e in ev if e["name"] == "outer"][0]
+    ev = [e for e in ev if e["name"] != "outer"]
+    names = sorted(e["name"] for e in ev)
+    assert names == (["mw.client.session"] + ["mw.client.step"] * 3
+                     + ["mw.exec.decode_many"] + ["mw.replica.queue"] * 3)
+    assert all(outer["t0"] <= e["t0"] < e["t1"] <= outer["t1"] for e in ev)
+    sess = [e for e in ev if e["name"] == "mw.client.session"][0]
+    assert sess["stats"]["span_id"] == root.span_id
+    assert sess["stats"]["worker"] == "client"
+    steps = {e["stats"]["span_id"]: e for e in ev
+             if e["name"] == "mw.client.step"}
+    # interleaved: the step started first waits longest, so ends last
+    assert len(steps) == 3
+    for q in (e for e in ev if e["name"] == "mw.replica.queue"):
+        s = steps[q["stats"]["parent_id"]]
+        assert s["t0"] <= q["t0"] < q["t1"] <= s["t1"]
+        assert q["stats"]["trace_id"] == root.trace_id
+        assert q["stats"]["worker"] == f"w{q['stats']['stage']}"
+    for s in steps.values():
+        assert s["stats"]["parent_id"] == root.span_id
+        assert sess["t0"] <= s["t0"] < s["t1"] <= sess["t1"]
+    ex = [e for e in ev if e["name"] == "mw.exec.decode_many"][0]
+    assert (ex["stats"]["stage"], ex["stats"]["width"]) == (1, 3)
+    assert "trace_id" not in ex["stats"]
+    # the ring keeps its kinds; the layer spans are the mirror's alone
+    assert sorted(s["kind"] for s in tr.spans()) == (["decode_step"] * 3
+                                                     + ["session"])
+
+
+def test_tracer_mirrors_nothing_without_a_profile_or_when_off(tmp_path):
+    on, off = Tracer(), Tracer(enabled=False)
+    before = on.open("mw.client.session", kind="session")
+    assert on.close(before) >= 0.0           # opened with no profile
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("outer"):
+            span = off.open("mw.client.step", kind="decode_step")
+            time.sleep(0.001)
+            assert off.close(span) >= 0.001   # counters still get time
+    assert [e["name"] for e in _mirrored(tmp_path)] == ["outer"]
+    assert off.spans() == [] and on.recorded == 1
+
+
 def test_connected_tree_detects_orphans_and_forests():
     def mk(span, parent, trace=1):
         return {"trace_id": trace, "span_id": span, "parent_id": parent,
@@ -293,6 +386,84 @@ def test_retired_replicas_leave_no_per_id_state(arun):
         cluster.shutdown()
 
     arun(scenario(), timeout=300.0)
+
+
+# ------------------------------------------------ stable program names
+@pytest.mark.parametrize("stage", [0, 1])
+def test_stage_programs_carry_their_stage_and_call_in_their_name(stage):
+    """XLA takes the jitted function's name for the program's module name,
+    which the device trace shows: ``s<stage>_<call>``, not ``<lambda>``."""
+    import jax.numpy as jnp
+    from repro.serving.executor import StageExecutor
+    from repro.serving.partition import (split_stages, stage_init_cache,
+                                         stage_params)
+    spec = split_stages(CFG, 2)[stage]
+    ex = StageExecutor(CFG, spec, stage_params(CFG, PARAMS, spec),
+                       max_len=16)
+    x = (jnp.zeros((1, 8), jnp.int32) if spec.first else
+         jnp.zeros((1, 8, CFG.d_model), CFG.activation_dtype))
+    step = x[:, :1]
+    cache = stage_init_cache(CFG, spec, 1, 16)
+    assert f"@jit_s{stage}_prefill" in ex._prefill.lower(
+        ex.sparams, x).as_text()
+    assert f"@jit_s{stage}_decode_many" in ex._decode_many.lower(
+        ex.sparams, (cache, cache), (step, step),
+        jnp.zeros(2, jnp.int32)).as_text()
+
+
+# ------------------------------------------------ host-path counters
+def test_host_path_counters_count_what_the_pipeline_served(arun):
+    """A ``[1, 2]`` pipeline's host-path sums, as ``replica_stats`` and
+    ``client_stats`` give them: each is zero where its count
+    (``decode_steps``, ``decode_batches``, tokens returned) is, and grows
+    with it; the executor call lies inside its dispatch; the empty polls
+    are every communicator's of the cluster."""
+    keys = ("decode_steps", "decode_batches", "decode_wait_s_sum",
+            "dispatch_s_sum", "exec_s_sum", "polls_empty", "stage")
+
+    def snap(server):
+        reps = {wid: {k: st[k] for k in keys}
+                for wid, st in server.replica_stats().items()}
+        polls = sum(w.comm.polls_empty
+                    for w in server.cluster.workers.values())
+        return reps, server.client_stats(), polls
+
+    async def scenario():
+        cluster = Cluster()
+        server = PipelineServer(cluster, MODEL, PARAMS, [1, 2], max_len=64)
+        await server.start()
+        await _warm(server, 3)
+        snaps, outs = [snap(server)], []
+        for seed in (5, 6):
+            outs.append(await asyncio.gather(*(
+                server.generate(p, 5, step_timeout=120.0)
+                for p in _prompts(3, seed=seed))))
+            snaps.append(snap(server))
+        cluster.shutdown()
+        return snaps, outs
+
+    snaps, outs = arun(scenario(), timeout=300.0)
+    for (reps0, client0, _), (reps1, client1, polls1) in zip(snaps,
+                                                             snaps[1:]):
+        assert polls1 == client1["polls_empty"] + sum(
+            st["polls_empty"] for st in reps1.values())
+        assert client1["polls_empty"] >= client0["polls_empty"] >= 0
+        assert client1["token_host_s_sum"] > client0["token_host_s_sum"]
+        for wid, st1 in reps1.items():
+            d = {k: st1[k] - reps0[wid][k] for k in keys}
+            assert all(st1[k] >= 0 for k in keys)
+            assert (d["decode_wait_s_sum"] > 0) == (d["decode_steps"] > 0)
+            assert (d["dispatch_s_sum"] > 0) == (d["decode_batches"] > 0)
+            assert (d["exec_s_sum"] > 0) == (d["decode_batches"] > 0)
+            # the call on its worker thread lies inside its dispatch
+            assert d["exec_s_sum"] <= d["dispatch_s_sum"]
+            assert d["polls_empty"] >= 0
+        # every stage served each of the three sessions' four decode steps
+        for stage in (0, 1):
+            assert sum(st1["decode_steps"] - reps0[w]["decode_steps"]
+                       for w, st1 in reps1.items()
+                       if st1["stage"] == stage) == 3 * 4
+    assert all(o.shape == (1, 5) for batch in outs for o in batch)
 
 
 # ------------------------------------------------------- hub export smoke
