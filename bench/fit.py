@@ -27,7 +27,7 @@ for _p in (HERE, os.path.join(ROOT, "src")):
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from lib import serve, spec as S, traffic as T  # noqa: E402
+from lib import spec as S, traffic as T  # noqa: E402
 
 
 def nbytes(tree) -> int:
@@ -49,8 +49,8 @@ def main(argv=None) -> int:
 
     bench = S.Benchmark(ROOT)
     cell = bench.cell(args.workload)
-    cfg, mix = S.load_json(cell.config_file), S.load_json(cell.traffic_file)
-    pcfg = serve.program_config(cfg)
+    (cfg, arch), mix = bench.config(cell), S.load_json(cell.traffic_file)
+    pcfg = arch.program_config(cfg, arch.sizes(cfg))
     max_len = int(mix["server"]["max_len"])
     n_stages = len(mix["server"]["replicas"])
     topo = topologies.get_topology_desc(platform="tpu",

@@ -69,17 +69,18 @@ def control_gap(ref: list[np.ndarray], low: list[np.ndarray]) -> float:
     return widest_gap(ref, [lg.argmax(-1) for lg in low])
 
 
-def served_gap(cfg: dict, seed: int, records: list,
+def served_gap(arch, cfg: dict, seed: int, records: list,
                control: bool = False) -> dict:
     """The program's reading (``gap``) over ``records`` and, with
-    ``control``, the control's (``control_gap``)."""
+    ``control``, the control's (``control_gap``); ``arch`` is the
+    configuration's layer module."""
     seqs, rows, served = teacher_forced(records)
-    ref = Reference(cfg, seed).logits(seqs, rows)
+    ref = Reference(arch, cfg, seed).logits(seqs, rows)
     out = {"gap": widest_gap(ref, served), "requests": len(records),
            "tokens": int(sum(len(t) for t in served)),
            "longest": int(max(len(s) for s in seqs) + 1)}
     if control:
-        low = Reference(cfg, seed, "fp8").logits(seqs, rows)
+        low = Reference(arch, cfg, seed, "fp8").logits(seqs, rows)
         out["control_gap"] = control_gap(ref, low)
     return out
 

@@ -9,7 +9,8 @@ from typing import Optional
 class Context:
     cell: str
     cfg: dict                      # the configuration file
-    sizes: dict                    # weights.sizes(cfg)
+    arch: object                   # its layer module, arch/<name>.py
+    sizes: dict                    # arch.sizes(cfg)
     stages: int
     mix: dict                      # the traffic mix file
     window: object                 # serve.Window

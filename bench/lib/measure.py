@@ -11,9 +11,6 @@ from __future__ import annotations
 import math
 
 
-from . import costs
-
-
 def percentile(values, q: float) -> float:
     """Nearest-rank percentile: the smallest value with at least q% of the
     values at or below it."""
@@ -100,7 +97,7 @@ def labelled(ctx, kind: str) -> dict | None:
     """Device seconds and calls of the traced window's ``kind`` programs
     (``prefill`` or ``decode``, every stage) beside the dispatches the
     executors counted; None where the trace names too few or too many of
-    them, so no reader builds on a label that was missed."""
+    them, so no reader builds on programs the trace failed to name."""
     if ctx.trace is None:
         return None
     v = ctx.trace["by_label"].get(kind)
@@ -112,11 +109,12 @@ def labelled(ctx, kind: str) -> dict | None:
     return {"s": v["s"], "calls": v["calls"], "counted": counted}
 
 
-def model_flops(sizes: dict, window) -> float:
+def model_flops(arch, sizes: dict, window) -> float:
     """Operations the window's tokens require: every prompt prefilled in
     the window (real positions, last-position logits) and every decode
-    step; work redone after a failure is not counted."""
-    return (sum(costs.prefill_flops(sizes, s)
+    step, as the layer module ``arch`` counts them; work redone after a
+    failure is not counted."""
+    return (sum(arch.prefill_flops(sizes, s)
                 for s in prompts_prefilled(window))
-            + sum(costs.decode_flops(sizes, p)
+            + sum(arch.decode_flops(sizes, p)
                   for p in decode_positions(window)))

@@ -1,6 +1,7 @@
-"""Drive the system under test: build the cell's ``PipelineServer`` from
-the configuration file and the seed, warm every program the cell's traffic
-reaches, run the open-loop window, and record what the metric readers read.
+"""Drive the system under test: build the cell's ``PipelineServer`` over
+the program configuration and weights that the layer module gives, warm
+every program the cell's traffic reaches, run the open-loop window, and
+record what the metric readers read.
 
 The program is imported from ``<checkout>/src``; nothing else of it is used
 but its public entry points (``PipelineServer.generate``, a stage
@@ -21,32 +22,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import traffic as T
-from . import weights as W
 
-#: tokens each warm-up and label request asks for: its prefill, then one
+#: tokens each warm-up request asks for: its prefill, then one
 #: single-session decode step
 PROBE_TOKENS = 2
-#: host annotations the trace reduction reads (lib.trace)
-LABEL_REQUEST = "bench:label:request:{stages}"
-LABEL_DECODE = "bench:label:decode"
-
-
-def program_config(cfg: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.models import DENSE, BlockGroup, ModelConfig
-    s = W.sizes(cfg)
-    prog = cfg["program"]
-    if cfg["torch_dtype"] != "bfloat16":
-        raise ValueError(f"unsupported dtype {cfg['torch_dtype']}")
-    return ModelConfig(
-        arch_id=prog["arch"], family="dense", num_layers=s["layers"],
-        d_model=s["d"], num_heads=s["h"], num_kv_heads=s["kv"],
-        head_dim=s["hd"], d_ff=s["f"], vocab_size=s["v"],
-        groups=(BlockGroup(DENSE, s["layers"]),), qk_norm=s["qk_norm"],
-        rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]), tie_embeddings=s["tied"],
-        param_dtype=jnp.bfloat16, activation_dtype=jnp.bfloat16,
-        attn_impl=prog["attn_impl"], source_cite=cfg["source"])
 
 
 def build_server(pcfg, params, srv: dict):
@@ -75,7 +54,7 @@ def _stage_input(server, ex, length: int):
     return jnp.zeros((1, length, cfg.d_model), cfg.activation_dtype)
 
 
-def _convoys(server, length: int, annotate: bool = False) -> None:
+def _convoys(server, length: int) -> None:
     """Each stage's public ``decode_many`` at every convoy size from two to
     the server's ``microbatch_max``, over one cache of ``length``
     positions: the program's own width buckets and convoy padding."""
@@ -84,12 +63,8 @@ def _convoys(server, length: int, annotate: bool = False) -> None:
         x = _stage_input(server, ex, 1)
         jax.block_until_ready((cache, x))
         for n in range(2, server.microbatch_max + 1):
-            args = ([cache] * n, [x] * n, [length] * n)
-            if annotate:
-                with jax.profiler.TraceAnnotation(LABEL_DECODE):
-                    jax.block_until_ready(ex.decode_many(*args))
-            else:
-                jax.block_until_ready(ex.decode_many(*args))
+            jax.block_until_ready(
+                ex.decode_many([cache] * n, [x] * n, [length] * n))
 
 
 async def warm(server, mix: dict, seconds: float, vocab: int) -> int:
@@ -103,20 +78,6 @@ async def warm(server, mix: dict, seconds: float, vocab: int) -> int:
         await server.generate(_prompt(n, vocab), PROBE_TOKENS)
     _convoys(server, min(lengths))
     return len(lengths)
-
-
-async def label(server, mix: dict, seconds: float, vocab: int) -> None:
-    """Name, in a traced run, the programs the window runs. Each prompt
-    length of the mix goes through ``generate`` once more inside the host
-    annotation ``bench:label:request:<stages>``: the window's own path, so
-    the trace reduction names its prefill and single-session decode
-    programs by their order in the annotation. Each convoy size runs once
-    inside ``bench:label:decode``."""
-    name = LABEL_REQUEST.format(stages=server.n_stages)
-    for n in T.prompt_lengths(mix, seconds):
-        with jax.profiler.TraceAnnotation(name):
-            await server.generate(_prompt(n, vocab), PROBE_TOKENS)
-    _convoys(server, min(T.prompt_lengths(mix, seconds)), annotate=True)
 
 
 @dataclasses.dataclass

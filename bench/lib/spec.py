@@ -1,10 +1,19 @@
-"""Find a cell, its configuration, its traffic mix and its metric readers by
-the names ``BENCHMARK.json`` gives them.
+"""Find a cell, its configuration, its layer module, its traffic mix and its
+metric readers by the names ``BENCHMARK.json`` gives them.
 
-Everything that belongs to one configuration, mix or metric sits in a file
-of its own, so adding one is adding a file:
+Everything that belongs to one configuration, architecture, mix or metric
+sits in a file of its own, so adding one is adding a file:
 
 * ``<bench>/configs/<config>.json`` (the path is the cell's ``file``),
+  which names its layer module under ``bench_arch``,
+* ``<bench>/arch/<bench_arch>.py``, the one place that knows the layer:
+  ``sizes(cfg)`` and, of those sizes ``s``, ``layer_leaves(s, layer)``,
+  ``global_leaves(s)``, ``pack(s, layers, globals)`` (the program's
+  parameter tree), ``program_config(cfg, s)``, the reference's
+  ``layer(x, p, index, s, cfg, fp8)`` (``index`` traced) and
+  ``head(x, globals, s, cfg, fp8)``, and the counts ``layer_params``,
+  ``prefill_flops``, ``decode_flops``, ``decode_stage_flops``,
+  ``stage_weight_bytes``, ``decode_kv_bytes``, ``decode_bytes``,
 * ``<bench>/traffic/<traffic>.json``,
 * ``<bench>/metrics/<metric>.py``, which defines ``read(ctx)``.
 """
@@ -107,14 +116,28 @@ class Benchmark:
 
     def reader(self, metric: str):
         """The ``read(ctx)`` function of ``metrics/<metric>.py``."""
-        path = os.path.join(self.bench_dir, "metrics", metric + ".py")
+        return self._module("metrics", metric).read
+
+    def arch(self, name: str):
+        """The layer module ``arch/<name>.py``."""
+        return self._module("arch", _name(name, "arch"))
+
+    def config(self, cell: Cell) -> tuple[dict, object]:
+        """A cell's configuration and the layer module it names."""
+        cfg = load_json(cell.config_file)
+        if "bench_arch" not in cfg:
+            raise SpecError(f"{cell.config_file} names no bench_arch")
+        return cfg, self.arch(cfg["bench_arch"])
+
+    def _module(self, kind: str, name: str):
+        path = os.path.join(self.bench_dir, kind, name + ".py")
         if not os.path.exists(path):
-            raise SpecError(f"metric {metric} has no reader at {path}")
-        mod_name = "bench_metric_" + re.sub(r"\W", "_", metric)
+            raise SpecError(f"{kind} {name}: no module at {path}")
+        mod_name = f"bench_{kind}_" + re.sub(r"\W", "_", name)
         spec = importlib.util.spec_from_file_location(mod_name, path)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
-        return mod.read
+        return mod
 
 
 def load_json(path: str) -> dict:

@@ -10,28 +10,31 @@ the reduction can be checked on a small recorded fixture.
   inside the window, idle gaps its complement.
 * Programs are the events of the per-program line (``XLA Modules``), keyed by
   name (on the TPU the name carries the program's id) and, where the event
-  carries one, a ``program_id`` stat. The benchmark names them itself:
-  before the window it sends requests and convoys through the program's
-  entry points inside host annotations (``bench:label:...``, see
-  :func:`labels`) and takes the program keys of the device events that ran
-  inside them. A label is ``prefill`` or ``decode``: the stage programs of
-  either kind, every stage together.
+  carries one, a ``program_id`` stat. The program names each jitted stage
+  call ``s<stage>_<call>``, which XLA takes for the module's name; the
+  kind of a program (its label) is read from that name (:func:`kind`):
+  ``prefill``, or ``decode`` for a single step and a convoy, every stage
+  together. Any other program has no kind.
 * The window is the host annotation ``bench:window``.
 * Each idle gap is named by the shortest host event that spans its middle:
   what the host was doing while the device waited.
 """
 from __future__ import annotations
 
-import bisect
 import glob
 import os
+import re
 import warnings
 from collections import defaultdict
 
 WINDOW = "bench:window"
-LABEL = "bench:label:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+#: a stage program's module name, ``jit_s<stage>_<call>``, then the id or
+#: nothing; the calls that prefill and that decode
+STAGE_PROGRAM = re.compile(
+    r"(?<![A-Za-z0-9])jit_s\d+_(prefill|decode|decode_many|decode_paged)"
+    r"(?![A-Za-z])")
 
 
 def _stats(event) -> dict:
@@ -113,40 +116,13 @@ def window(events: list[dict]) -> tuple[float, float] | None:
     return None
 
 
-def labels(events: list[dict]) -> dict[str, str]:
-    """Program key -> ``prefill`` or ``decode`` from the label annotations.
-
-    ``bench:label:request:<n>`` wraps one request of two tokens through an
-    n-stage pipeline: its 2n longest device programs, in order of start,
-    are the prefill of each stage and then one decode step of each.
-    ``bench:label:decode`` wraps one convoy through one stage: the longest
-    device program inside it is that stage's convoy program.
-    """
-    spans = [(e["start_ns"], e["start_ns"] + e["dur_ns"],
-              e["name"][len(LABEL):])
-             for e in events
-             if e["name"].startswith(LABEL) and not is_device(e["plane"])]
-    modules = sorted((e for e in events if is_device(e["plane"])
-                      and e["line"] == MODULES_LINE),
-                     key=lambda e: e["start_ns"])
-    starts = [e["start_ns"] for e in modules]
-    out: dict[str, str] = {}
-    for a, b, label in spans:
-        inside = modules[bisect.bisect_left(starts, a):
-                         bisect.bisect_right(starts, b)]
-        if label.startswith("request:"):
-            n = int(label.split(":")[1])
-            top = sorted(sorted(inside, key=lambda e: -e["dur_ns"])[:2 * n],
-                         key=lambda e: e["start_ns"])
-            if len(top) != 2 * n:
-                continue
-            for i, e in enumerate(top):
-                out.setdefault(program_key(e),
-                               "prefill" if i < n else "decode")
-        elif inside:
-            longest = max(inside, key=lambda e: e["dur_ns"])
-            out.setdefault(program_key(longest), label)
-    return out
+def kind(name: str) -> str | None:
+    """``prefill`` or ``decode`` for a stage program's module name, else
+    None."""
+    m = STAGE_PROGRAM.search(name)
+    if m is None:
+        return None
+    return "prefill" if m.group(1) == "prefill" else "decode"
 
 
 def reduce(events: list[dict], top: int = 10) -> dict | None:
@@ -171,7 +147,6 @@ def reduce(events: list[dict], top: int = 10) -> dict | None:
     used = [p for p in planes if busy_by_plane[p] > 0]
     if not used:
         return None
-    names = labels(events)
     programs: dict[str, dict] = defaultdict(lambda: {"ns": 0.0, "calls": 0})
     by_label: dict[str, dict] = defaultdict(lambda: {"ns": 0.0, "calls": 0})
     for e in dev:
@@ -180,7 +155,7 @@ def reduce(events: list[dict], top: int = 10) -> dict | None:
         key = program_key(e)
         programs[key]["ns"] += e["dur_ns"]
         programs[key]["calls"] += 1
-        label = names.get(key)
+        label = kind(e["name"])
         if label is not None:
             by_label[label]["ns"] += e["dur_ns"]
             by_label[label]["calls"] += 1
@@ -204,11 +179,11 @@ def reduce(events: list[dict], top: int = 10) -> dict | None:
         "busy_s": sum(busy_by_plane[p] for p in used) / len(used) / 1e9,
         "devices": len(used),
         "programs": {k: {"s": v["ns"] / 1e9, "calls": v["calls"],
-                         "label": names.get(k)} for k, v in ranked},
+                         "label": kind(k)} for k, v in ranked},
         "by_label": {k: {"s": v["ns"] / 1e9, "calls": v["calls"]}
                      for k, v in by_label.items()},
-        "device_ops": [[(names.get(k) or "") + ("/" if names.get(k) else "")
-                        + k, v["ns"] / 1e9] for k, v in ranked[:top]],
+        "device_ops": [[(kind(k) or "") + ("/" if kind(k) else "") + k,
+                        v["ns"] / 1e9] for k, v in ranked[:top]],
         "idle_gaps": [[w, s] for w, s in idle],
     }
 

@@ -8,7 +8,7 @@ from lib import measure
 def read(ctx):
     if ctx.trace is None or ctx.peaks is None:
         return None
-    flops = measure.model_flops(ctx.sizes, ctx.window)
+    flops = measure.model_flops(ctx.arch, ctx.sizes, ctx.window)
     if flops <= 0:
         return None
     return 100.0 * flops / (ctx.trace["window_s"] * ctx.peaks["bf16_flops"])

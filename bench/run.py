@@ -3,8 +3,9 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Builds the cell's configuration (``configs/<config>.json``) with weights
-made on the device from ``--seed``, the cell's ``PipelineServer``, warms
+Builds the cell's configuration (``configs/<config>.json``, whose layer
+module ``arch/<bench_arch>.py`` lays out the program's parameters, weights
+made on the device from ``--seed``), the cell's ``PipelineServer``, warms
 every program the cell's traffic (``traffic/<mix>.json``) reaches, offers
 that traffic open loop for ``--seconds``, then checks a sample of what was
 served against the plain reference. The last line of standard output is
@@ -16,7 +17,7 @@ last lines of standard error.
 
 Exits 1 without a result when JAX finds no TPU, or fewer chips than the cell
 asks for, or when a metric BENCHMARK.json lists for the cell cannot be read
-(a traced run whose labels missed the programs the window ran, say).
+(a traced run whose stage programs the trace does not name, say).
 """
 from __future__ import annotations
 
@@ -116,13 +117,13 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool, *,
     if dev.platform == "tpu":
         use_compile_cache(root)
     compile_log = serve.CompileLog()
-    cfg = S.load_json(cell.config_file)
+    cfg, arch = bench.config(cell)
     mix = S.load_json(cell.traffic_file)
-    sizes = W.sizes(cfg)
-    pcfg = serve.program_config(cfg)
+    sizes = arch.sizes(cfg)
+    pcfg = arch.program_config(cfg, sizes)
     reqs = T.schedule(mix, seconds, seed, sizes["v"])
     from repro.models import build_model
-    params = W.program_params(sizes, seed)
+    params = W.program_params(arch, sizes, seed)
     _check_layout(build_model(pcfg), params)
     log("weights made", t_start)
     server = serve.build_server(pcfg, params, mix["server"])
@@ -137,8 +138,6 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool, *,
         if trace:
             jax.profiler.start_trace(trace_dir,
                                      profiler_options=_profile_opts())
-            await serve.label(server, mix, seconds, sizes["v"])
-            log("programs labelled", t_start)
 
     def on_open():
         if trace:
@@ -175,9 +174,11 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool, *,
     gc.collect()
 
     chosen = check.sample(win, seed, SAMPLE_TOKENS, SAMPLE_REQUESTS)
-    sampled = check.served_gap(cfg, seed, chosen, control) if chosen else {}
+    sampled = check.served_gap(arch, cfg, seed, chosen,
+                                     control) if chosen else {}
     log("reference compared", t_start)
-    ctx = Context(cell=cell.name, cfg=cfg, sizes=sizes, stages=n_stages,
+    ctx = Context(cell=cell.name, cfg=cfg, arch=arch, sizes=sizes,
+                  stages=n_stages,
                   mix=mix, window=win, trace=reduced, peaks=peak,
                   setup_s=win.setup_s)
     kind = "per_layer" if trace else "end_to_end"

@@ -78,11 +78,12 @@ def main(argv=None) -> int:
         return 1
     R.use_compile_cache(ROOT)
     log = serve.CompileLog()
-    cell = S.Benchmark(ROOT).cell(args.workload)
-    cfg, mix0 = S.load_json(cell.config_file), S.load_json(cell.traffic_file)
-    sizes = W.sizes(cfg)
-    pcfg = serve.program_config(cfg)
-    params = W.program_params(sizes, args.seed)
+    bench = S.Benchmark(ROOT)
+    cell = bench.cell(args.workload)
+    (cfg, arch), mix0 = bench.config(cell), S.load_json(cell.traffic_file)
+    sizes = arch.sizes(cfg)
+    pcfg = arch.program_config(cfg, sizes)
+    params = W.program_params(arch, sizes, args.seed)
     for rate in (float(r) for r in args.rates.split(",")):
         mix = dict(mix0, rate_rps=rate)
         t0 = time.monotonic()

@@ -14,7 +14,7 @@ sys.path.insert(0, os.path.dirname(HERE))
 import control  # noqa: E402
 from lib import check, spec as S  # noqa: E402
 from lib.reference import Reference  # noqa: E402
-from tests_support import TINY_CONFIG, make  # noqa: E402
+from tests_support import ROOT, TINY_CONFIG, make  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -48,7 +48,8 @@ def test_widest_gap_and_control_gap():
 
 
 def test_reference_rows_ignore_right_padding():
-    ref = Reference(dict(TINY_CONFIG), 5)
+    ref = Reference(S.Benchmark(ROOT).arch("dense_gqa"), dict(TINY_CONFIG),
+                    5)
     seq = np.arange(1, 20, dtype=np.int32)
     a = ref.logits([seq], [np.arange(19)])[0]
     b = ref.logits([seq[:10]], [np.arange(10)])[0]

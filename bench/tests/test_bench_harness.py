@@ -42,8 +42,9 @@ def checkout(tmp_path_factory):
     return root
 
 
-def _run(root, cell, seed=11, trace=False, fault=None, strict=True):
-    return R.run(root, cell, seed, SECONDS, trace, bench=S.Benchmark(root),
+def _run(root, cell, seed=11, trace=False, fault=None, strict=True,
+         seconds=SECONDS):
+    return R.run(root, cell, seed, seconds, trace, bench=S.Benchmark(root),
                  require_tpu=False, strict=strict, t_start=time.monotonic(),
                  fault=fault)
 
@@ -71,7 +72,10 @@ def test_a_tiny_run_is_correct_and_reports_its_metrics(checkout):
     assert res["metrics"]["compiles_in_window"]["value"] == 0.0
     assert list(res)[-1] == "checks"
     assert res["checks"]["logit_gap"]["limit"] == 0.1
-    res = _run(checkout, "tiny-tiny-mix", seed=12)
+    # every end-to-end metric read strict: half the tiny mix's load, so the
+    # slow pipeline on a CPU still delivers some tokens two by two in the
+    # window for the inter-token gaps
+    res = _run(checkout, "tiny-tiny-slow", seed=12, seconds=2 * SECONDS)
     assert res["correct"], res["checks"]
     assert set(res["metrics"]) == {"tokens_per_s", "ttft_p90_ms",
                                    "itl_p95_ms", "setup_s"}
@@ -79,7 +83,9 @@ def test_a_tiny_run_is_correct_and_reports_its_metrics(checkout):
 
 @pytest.mark.parametrize("fault", sorted(faults.FAULTS))
 def test_a_broken_timed_path_is_not_correct(checkout, fault):
-    res = _run(checkout, "tiny-tiny-mix", seed=13, fault=fault)
+    # correct is what this checks; a loaded CPU may leave the 3 s window
+    # without an inter-token gap to read, which the strict run would raise
+    res = _run(checkout, "tiny-tiny-mix", seed=13, fault=fault, strict=False)
     assert not res["correct"]
     gap = res["checks"]["logit_gap"]
     assert gap["value"] > gap["limit"], res["checks"]

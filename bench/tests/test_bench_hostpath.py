@@ -44,7 +44,8 @@ def _window(start, end, tokens=12):
 
 
 def _read(name, win, trace=None):
-    ctx = Context(cell="c", cfg={}, sizes={}, stages=2, mix={}, window=win,
+    ctx = Context(cell="c", cfg={}, arch=None, sizes={}, stages=2, mix={},
+                  window=win,
                   trace=trace, peaks=None, setup_s=win.setup_s)
     return Benchmark(ROOT).reader(name)(ctx)
 

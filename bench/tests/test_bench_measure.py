@@ -38,7 +38,8 @@ def _window(records, seconds=10.0, steps=0, batches=0, prefills=0):
 
 
 def _ctx(win, trace=None):
-    return Context(cell="c", cfg={}, sizes={}, stages=1, mix={}, window=win,
+    return Context(cell="c", cfg={}, arch=None, sizes={}, stages=1, mix={},
+                   window=win,
                    trace=trace, peaks=None, setup_s=win.setup_s)
 
 

@@ -1,5 +1,5 @@
 """Trace reduction: busy time as a union, idle gaps named by what the host
-did, device time per program named by the label probes."""
+did, device time per program of the kind its module name says."""
 import json
 import os
 import sys
@@ -37,21 +37,35 @@ def test_window_busy_and_idle(events):
 
 
 def test_programs_are_named_by_the_label_probes(events):
-    names = TR.labels(events)
-    # the request's two longest programs in order: prefill, then decode;
-    # the eager pad between them and the broadcast before the convoy are
-    # too short to be either
-    assert names == {"jit__lambda#11": "prefill",
-                     "jit__lambda#12": "decode",
-                     "jit__many#13": "decode"}
+    # the stage programs' module names give their kind; the eager pad and
+    # broadcast have none
     r = TR.reduce(events)
     assert r["by_label"]["prefill"] == {"s": pytest.approx(300e-9),
                                         "calls": 1}
     assert r["by_label"]["decode"] == {"s": pytest.approx(150e-9),
                                        "calls": 2}
     assert r["programs"]["jit_pad"]["label"] is None
+    assert r["programs"]["jit_s0_decode_many#13"]["label"] == "decode"
     top = [name for name, _ in r["device_ops"]]
-    assert top[0] == "prefill/jit__lambda#11"
+    assert top[0] == "prefill/jit_s0_prefill#11"
+
+
+@pytest.mark.parametrize("name, kind", [
+    ("jit_s0_prefill", "prefill"),
+    ("jit_s12_prefill(8123)", "prefill"),
+    ("jit_s1_decode", "decode"),
+    ("jit_s1_decode_4868979686324340714_", "decode"),
+    ("jit_s0_decode_many(7607214138940593126)", "decode"),
+    ("jit_s3_decode_paged", "decode"),
+    ("jit_s1_verify_many", None),
+    ("jit_s0_score", None),
+    ("jit_s0_propose", None),
+    ("jit_s0_decoder", None),
+    ("jit_pad", None),
+    ("jit__lambda", None),
+])
+def test_a_program_has_the_kind_its_module_name_says(name, kind):
+    assert TR.kind(name) == kind
 
 
 def test_no_window_or_no_device_gives_nothing(events):

@@ -11,7 +11,8 @@ BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
 
 TINY_CONFIG = {
-    "name": "tiny", "source": "test", "hidden_act": "silu",
+    "name": "tiny", "source": "test", "bench_arch": "dense_gqa",
+    "hidden_act": "silu",
     "hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
     "num_hidden_layers": 2, "num_key_value_heads": 2, "head_dim": 16,
     "rms_norm_eps": 1e-6, "rope_theta": 10000.0,
@@ -36,13 +37,14 @@ TINY_MIX = {
 
 def make(tmp: str, metrics=None, mixes=None) -> str:
     """Lay out ``tmp`` as a checkout: BENCHMARK.json, the tiny config and
-    mixes, and copies of the real metric readers and lib. Returns its
-    root."""
+    mixes, and copies of the real metric readers, layer modules and lib.
+    Returns its root."""
     bench = os.path.join(tmp, "bench")
     for sub in ("configs", "traffic", "metrics"):
         os.makedirs(os.path.join(bench, sub), exist_ok=True)
-    shutil.copytree(os.path.join(BENCH, "lib"), os.path.join(bench, "lib"),
-                    dirs_exist_ok=True)
+    for sub in ("lib", "arch"):
+        shutil.copytree(os.path.join(BENCH, sub), os.path.join(bench, sub),
+                        dirs_exist_ok=True)
     for f in os.listdir(os.path.join(BENCH, "metrics")):
         if f.endswith(".py"):
             shutil.copy(os.path.join(BENCH, "metrics", f),
