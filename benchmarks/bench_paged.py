@@ -104,7 +104,7 @@ def _capacity_scenario(tiny: bool) -> dict:
         t = x.shape[1]
         for _ in range(2):           # a couple of live decode steps each
             last = np.asarray(out)
-            last = last[:, -1] if last.ndim == 3 else last  # prefill (B,S,V)
+            last = last[:, -1] if last.ndim == 3 else last  # prefill (B,1,V)
             tok = last.argmax(-1).astype(np.int32).reshape(1, 1)
             out, cache = ex.decode(cache, jax.numpy.asarray(tok), t)
             t += 1

@@ -73,7 +73,9 @@ code that does the work, not into the ring:
 ``mw.replica.gather``     decode handler start → convoy submitted
 ``mw.replica.dispatch``   executor call submitted → coroutine resumed
 ``mw.exec.<call>``        the executor call on its worker thread
-                          (``prefill``, ``decode``, ``decode_many``, ...)
+                          (``prefill``, ``decode``, ``decode_many``, ...);
+                          a prefill's stats hold its real ``tokens`` and
+                          the ``bucket`` of positions it computes
 ``mw.replica.forward``    result sent on to the next stage or the client
 ========================  ==================================================
 """
@@ -287,14 +289,16 @@ class Tracer:
     # ------------------------------------------------------- open / close
     def open(self, name: str, parent: Optional[TraceContext] = None, *,
              kind: str = "", worker: str = "", stage: int = -1,
-             width: int = 0) -> Span:
+             width: int = 0, **counts: int) -> Span:
         """Start a span now, where the work starts. While a JAX profile
         records it is also the annotation ``name`` (``mw.<layer>.<what>``)
-        on the profile's clock; with ``kind`` it goes into the ring at
-        :meth:`close`, as a child of ``parent`` (a root when ``parent`` is
-        None). The returned span always carries its start, so callers keep
-        their counters from :meth:`close` whether tracing is on or not.
-        Safe from worker threads when ``kind`` is empty."""
+        on the profile's clock, with ``width`` and ``counts`` (a prefill's
+        ``tokens`` and ``bucket``) among its stats; with ``kind`` it goes
+        into the ring at :meth:`close`, as a child of ``parent`` (a root
+        when ``parent`` is None). The returned span always carries its
+        start, so callers keep their counters from :meth:`close` whether
+        tracing is on or not. Safe from worker threads when ``kind`` is
+        empty."""
         t0 = time.monotonic()
         if not self.enabled:
             return Span(t0, None, "", worker, None)
@@ -310,6 +314,7 @@ class Tracer:
                              parent_id=ctx.parent_id)
             if width:
                 stats["width"] = width
+            stats.update(counts)
             ann = TraceAnnotation(name, **stats)
             ann.__enter__()
         return Span(t0, ctx, kind, worker, ann)

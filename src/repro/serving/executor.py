@@ -111,8 +111,9 @@ class StageExecutor:
             lambda sp, x: stage_forward(cfg, spec, sp, x, tokens_in=tokens_in))
         self._prefill = _named_jit(
             f"{self._tag}_prefill",
-            lambda sp, x: stage_prefill(cfg, spec, sp, x, max_len,
-                                        tokens_in=tokens_in))
+            lambda sp, x, n=None: stage_prefill(cfg, spec, sp, x, max_len,
+                                                tokens_in=tokens_in,
+                                                length=n))
         self._decode = _named_jit(
             f"{self._tag}_decode",
             lambda sp, c, x, t: stage_decode(cfg, spec, sp, c, x, t,
@@ -188,7 +189,9 @@ class StageExecutor:
                       "paged_decode_batches": 0, "paged_degrades": 0,
                       "verify_batches": 0, "verify_steps": 0,
                       "verify_tokens": 0, "propose_calls": 0,
-                      "propose_tokens": 0}
+                      "propose_tokens": 0,
+                      # real and bucketed positions prefilled
+                      "prefill_tokens": 0, "prefill_positions": 0}
         #: fused convoy widths already compiled (first-dispatch timing)
         self._widths_seen: set[int] = set()
         #: fused verify (width, K) shapes already compiled — part of the
@@ -240,8 +243,17 @@ class StageExecutor:
         """Teacher-forced forward: tokens/hidden (B,S[,D]) -> full output."""
         return self._timed("score_calls", self._score, x)
 
+    def prefill_len(self, s: int) -> int:
+        """Positions the prefill of ``s`` real ones computes: its bucket,
+        where this stage pads."""
+        if self.pad_seq:
+            return max(s, min(self._bucket(s), self.max_len))
+        return s
+
     def prefill(self, x: jax.Array) -> tuple[jax.Array, Any]:
-        """History (B,S[,D]) -> (output sliced back to S, session cache).
+        """History (B,S[,D]) -> (output, session cache). The output is the
+        hidden state sliced back to S positions, or on the last stage the
+        logits (B,1,V) of the last real position.
 
         In paged mode the contiguous prefill result is installed into the
         shared PagePool (leading full pages deduped against the prefix
@@ -249,14 +261,16 @@ class StageExecutor:
         returned instead; on template mismatch or pool exhaustion the
         session simply keeps the contiguous cache."""
         x0, s = x, x.shape[1]
-        if self.pad_seq:
-            sp = min(self._bucket(s), self.max_len)
-            if sp > s:
-                pad = [(0, 0), (0, sp - s)] + [(0, 0)] * (x.ndim - 2)
-                x = jnp.pad(x, pad)
+        sp = self.prefill_len(s)
+        if sp > s:
+            pad = [(0, 0), (0, sp - s)] + [(0, 0)] * (x.ndim - 2)
+            x = jnp.pad(x, pad)
         self._prefill_shapes_seen.add((tuple(x.shape), str(x.dtype)))
-        out, cache = self._timed("prefill_calls", self._prefill, x)
-        if out.shape[1] != s:
+        out, cache = self._timed("prefill_calls", self._prefill, x,
+                                 np.int32(s))
+        self.stats["prefill_tokens"] += x.shape[0] * s
+        self.stats["prefill_positions"] += x.shape[0] * sp
+        if not self.spec.last and out.shape[1] != s:
             out = out[:, :s]
         if self.paged:
             keys = kvpool.prefix_chunk_keys(x0, s, self.page_size)
@@ -808,7 +822,7 @@ class StageExecutor:
             # go through the jitted callable directly: prefill() would
             # re-bucket (already-bucketed shapes pass through unchanged) and
             # pollute the first-call timing stats
-            out, cache = self._prefill(self.sparams, x)
+            out, cache = self._prefill(self.sparams, x, np.int32(shape[1]))
             jax.block_until_ready(out)
             self._prefill_shapes_seen.add((tuple(shape), str(dtype)))
             dispatches += 1

@@ -103,8 +103,12 @@ def stage_forward(cfg: ModelConfig, spec: StageSpec, sparams: dict,
 
 
 def stage_prefill(cfg: ModelConfig, spec: StageSpec, sparams: dict,
-                  x: jax.Array, max_len: int, *, tokens_in: bool):
-    """Prefill + decode-cache build for one stage."""
+                  x: jax.Array, max_len: int, *, tokens_in: bool,
+                  length: Optional[jax.Array] = None):
+    """Prefill + decode-cache build for one stage. ``x`` may be right-padded
+    past the ``length`` real positions (default: all of them). The last
+    stage returns the logits (B,1,V) of row ``length - 1`` only, the one
+    row a caller reads; the others return every position's hidden state."""
     if tokens_in:
         x = tfm.embed_tokens(cfg, sparams, x)
     bsz, s = x.shape[:2]
@@ -118,7 +122,9 @@ def stage_prefill(cfg: ModelConfig, spec: StageSpec, sparams: dict,
                                           mrope=None, shared=shared)
         new_cache.append(nc)
     if spec.last:
-        return tfm.lm_logits(cfg, sparams, x), new_cache
+        n = s if length is None else length
+        last = jax.lax.dynamic_slice_in_dim(x, n - 1, 1, axis=1)
+        return tfm.lm_logits(cfg, sparams, last), new_cache
     return x, new_cache
 
 

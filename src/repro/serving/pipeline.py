@@ -85,6 +85,7 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import functools
 import itertools
 import time
 from collections import deque
@@ -339,14 +340,14 @@ class _Replica:
         if span is not None:
             self.server.tracer.close(span)
 
-    def _exec(self, name: str, width: int, fn, *args):
+    def _exec(self, name: str, width: int, fn, *args, **counts: int):
         """Run one executor call on its worker thread inside the span
-        ``name`` (``mw.exec.<call>``); a decode call's host seconds go to
-        ``exec_s_sum``. One call at a time per replica: its serve loop
-        awaits each."""
+        ``name`` (``mw.exec.<call>``, with ``counts`` among its stats); a
+        decode call's host seconds go to ``exec_s_sum``. One call at a time
+        per replica: its serve loop awaits each."""
         tracer = self.server.tracer
         span = tracer.open(name, worker=self.worker_id, stage=self.stage,
-                           width=width)
+                           width=width, **counts)
         try:
             return fn(*args)
         finally:
@@ -455,14 +456,16 @@ class _Replica:
         tracer = server.tracer
         span = tracer.open("mw.replica.dispatch", env.trace,
                            worker=self.worker_id, stage=self.stage, width=1)
+        s = int(env.payload.shape[1])
         try:
             y, cache = await loop.run_in_executor(
-                None, self._exec, "mw.exec.prefill", 1, ex.prefill,
-                env.payload)
+                None, functools.partial(
+                    self._exec, "mw.exec.prefill", 1, ex.prefill,
+                    env.payload, tokens=s, bucket=ex.prefill_len(s)))
         finally:
             tracer.close(span)
         if server._is_last(self.stage):
-            y = y[:, -1]              # client only needs last-position logits
+            y = y[:, -1]              # (B,1,V): the last real row's logits
         sid = env.session_id
         batch = int(env.payload.shape[0])
         # -- decode home: where this session's stage slice will live -------
